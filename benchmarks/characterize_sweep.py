@@ -23,6 +23,7 @@ import time
 import numpy as np
 
 from benchmarks.common import RESULTS_DIR, camera_factory, emit, ensure_dir
+from repro.compile_cache import enable_compile_cache
 from repro.core import grid_engine
 from repro.core import knobs as K
 from repro.core.characterization import characterize
@@ -32,6 +33,7 @@ ROOT_OUT = os.path.join(os.path.dirname(os.path.dirname(
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--clip-len", type=int, default=24,
                     help="standard calibration clip length (frames)")

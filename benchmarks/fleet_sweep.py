@@ -55,6 +55,7 @@ import numpy as np
 
 from benchmarks.common import (RESULTS_DIR, emit, ensure_dir,
                                synthetic_controller_table)
+from repro.compile_cache import enable_compile_cache
 from repro.core.characterization import LatencyRegression
 from repro.core.controller import (ControllerConfig, ControllerParams,
                                    FleetController, JaxControllerTables,
@@ -321,8 +322,12 @@ def run_sharded_child(n: int, *, devices: int, polls: int,
                       repeats: int) -> float:
     """Measure ``time_whole_poll`` on a forced ``devices``-device host mesh
     in a SUBPROCESS: ``--xla_force_host_platform_device_count`` only takes
-    effect before jax initializes, which this (parent) process already did."""
+    effect before jax initializes, which this (parent) process already did.
+    The child is pinned to the CPU: the mesh it measures is virtual CPU
+    devices, and a parent that holds an accelerator keeps it from any
+    child."""
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = (f"--xla_force_host_platform_device_count={devices} "
                         + env.get("XLA_FLAGS", "")).strip()
     proc = subprocess.run(
@@ -338,6 +343,7 @@ def run_sharded_child(n: int, *, devices: int, polls: int,
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--repeats", type=int, default=5,
                     help="best-of-N timing repeats (CI runners are noisy)")

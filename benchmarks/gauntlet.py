@@ -54,6 +54,7 @@ from collections import Counter
 import numpy as np
 
 from benchmarks.common import Timer, emit, synthetic_controller_table
+from repro.compile_cache import enable_compile_cache
 from repro.core.channel import calibrated_channel
 from repro.core.characterization import fit_latency_regression
 from repro.core.scenario import (BrokerOverload, CameraCrash, CameraMigrate,
@@ -307,6 +308,7 @@ def run_gauntlet(*, seed: int = 7, full: bool = False,
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--seed", type=int, default=7)
     ap.add_argument("--full", action="store_true",

@@ -519,6 +519,9 @@ def fig16_latency_breakdown() -> dict:
 
 if __name__ == "__main__":
     import sys
+
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if "fig12" in sys.argv[1:]:
         fig12_e2e_latency_accuracy()
     else:

@@ -14,8 +14,11 @@ import argparse
 import sys
 import traceback
 
+from repro.compile_cache import enable_compile_cache
+
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default=None,
                     help="run only experiments whose name contains this")

@@ -17,6 +17,7 @@ Run:  PYTHONPATH=src python examples/multi_camera_pedestrian.py
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs.mez_edge import CONFIG as EDGE
 from repro.core.api import QosBounds
 from repro.core.broker import MezSystem
@@ -32,6 +33,7 @@ TIGHTENED_LATENCY = 0.060           # mid-run renegotiation target, seconds
 
 
 def main() -> None:
+    enable_compile_cache()
     table = characterize(
         lambda: SyntheticCamera(CameraConfig(dynamics="complex",
                                              seed=EDGE.seed)),

@@ -8,6 +8,7 @@ quality.  Run:  PYTHONPATH=src python examples/quickstart.py
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs.mez_edge import CONFIG as EDGE
 from repro.core.api import QosBounds
 from repro.core.broker import MezSystem
@@ -18,6 +19,7 @@ from repro.data.camera import CameraConfig, SyntheticCamera
 
 
 def main() -> None:
+    enable_compile_cache()
     # 1. offline characterization (paper Section 2): knob grid -> (size, F1)
     print("characterizing knob grid on a calibration clip ...")
     table = characterize(

@@ -36,7 +36,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.kernels import ops as kops
 from repro.kernels import ref as kref
 
 __all__ = ["CompressionLevel", "LEVELS", "compressed_mean",

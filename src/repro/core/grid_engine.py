@@ -53,7 +53,7 @@ from repro.core import knobs as K
 from repro.kernels import frame_knobs as FK
 
 __all__ = ["GridCharacterization", "WireSizeProxy", "run_grid",
-           "refresh_tables", "PIXEL_DELTA"]
+           "stage_clip", "refresh_tables", "PIXEL_DELTA"]
 
 PIXEL_DELTA = 8.0        # knobs.frame_difference's noise-robust change delta
 _FRAME_BUCKET = 16       # frame-axis padding so jit caches are shared
@@ -84,8 +84,10 @@ def _transform_group(frames: jax.Array, ry, rx, bys, bxs, cs: int,
     frames): payload u8 [S,F,P,oh,ow], proxy feats [S,F,6], and the
     detector's background diff [S,F-1,gh,gw] (frame 0 is the background).
 
-    The colorspace/artifact stages are the kernel's own helpers vmapped over
-    the clip, so the twin cannot drift from the Pallas math.  ``art_modes``
+    The twin computes the knob pipeline in plain f32 (``_to_planes``,
+    ``_artifact_masks`` and the plan's float operators); the kernel rounds
+    the same stages exactly, so the two can differ by one grey level where
+    a value sits on a rounding tie.  ``art_modes``
     is the plan's own mode tuple (artifact-major setting blocks of
     ``S // len(art_modes)`` blur settings each): each block applies the
     mask of its ACTUAL mode id, exactly like the kernel's per-setting
@@ -364,6 +366,25 @@ def _label_host(mask: np.ndarray) -> tuple[np.ndarray, int]:
     return out, 0                                   # background label
 
 
+def stage_clip(background: np.ndarray, frames: list[np.ndarray]):
+    """The grid sweep's device inputs for one calibration clip: the frame
+    stack ``[background, *frames, padding]`` (padded with the background
+    to a ``_FRAME_BUCKET`` multiple so jit caches are shared), the same
+    stack shifted by one (knob5's previous frames), the background, and
+    knob4's per-frame enable -- off for frame 0 (the detector's background
+    payload) and the padding tail.  Returns ``(frames, prev, background,
+    enable)`` as device arrays."""
+    n_real = len(frames) + 1
+    n_pad = -(-n_real // _FRAME_BUCKET) * _FRAME_BUCKET
+    stack = np.stack([background] + list(frames)
+                     + [background] * (n_pad - n_real)).astype(np.uint8)
+    enable = np.zeros(n_pad, np.int32)
+    enable[1:n_real] = 1
+    return (jnp.asarray(stack),
+            jnp.asarray(np.concatenate([stack[:1], stack[:-1]])),
+            jnp.asarray(background.astype(np.uint8)), jnp.asarray(enable))
+
+
 def run_grid(background: np.ndarray, frames: list[np.ndarray], *,
              detector_thresh: float = 28.0, min_area: int = 12,
              include_artifact: bool = False,
@@ -394,17 +415,7 @@ def run_grid(background: np.ndarray, frames: list[np.ndarray], *,
     art_modes = (0, 1, 2) if include_artifact else (0,)
     n_clip = len(frames)
     n_real = n_clip + 1                                  # +1: background
-    n_pad = -(-n_real // _FRAME_BUCKET) * _FRAME_BUCKET
-    stack = np.stack([background] + list(frames)
-                     + [background] * (n_pad - n_real)).astype(np.uint8)
-    fj = jnp.asarray(stack)
-    prevj = jnp.asarray(np.concatenate([stack[:1], stack[:-1]]))
-    bgj = jnp.asarray(background.astype(np.uint8))
-    # knob4 must not fire on frame 0 (the detector's background payload)
-    # or on the padding tail
-    enable = np.zeros(n_pad, np.int32)
-    enable[1:n_real] = 1
-    enj = jnp.asarray(enable)
+    fj, prevj, bgj, enj = stage_clip(background, frames)
 
     change_counts_dev = _change_counts(
         jnp.asarray(np.stack(frames).astype(np.uint8)))

@@ -40,10 +40,10 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *, scale: float, causal: bool,
 
     def body(kv_i, carry):
         m, l, acc = carry
-        k = pl.load(k_ref, (0, pl.ds(kv_i * block_k, block_k), 0,
-                            slice(None))).astype(jnp.float32)   # [BK, D]
-        v = pl.load(v_ref, (0, pl.ds(kv_i * block_k, block_k), 0,
-                            slice(None))).astype(jnp.float32)
+        k = k_ref[0, pl.ds(kv_i * block_k, block_k), 0,
+                     :].astype(jnp.float32)   # [BK, D]
+        v = v_ref[0, pl.ds(kv_i * block_k, block_k), 0,
+                     :].astype(jnp.float32)
         s = q @ k.T                                             # [BQ, BK]
         k_pos = kv_i * block_k + jax.lax.broadcasted_iota(
             jnp.int32, (bq, block_k), 1)

@@ -38,10 +38,10 @@ def _wkv_kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, y_ref, s_out_ref,
 
     def chunk(ci, _):
         sl = (0, pl.ds(ci * block_t, block_t), 0, slice(None))
-        r = pl.load(r_ref, sl).astype(jnp.float32)   # [BT, K] (ints squeeze)
-        k = pl.load(k_ref, sl).astype(jnp.float32)
-        v = pl.load(v_ref, sl).astype(jnp.float32)
-        lw = pl.load(lw_ref, sl).astype(jnp.float32)
+        r = r_ref[sl].astype(jnp.float32)            # [BT, K] (ints squeeze)
+        k = k_ref[sl].astype(jnp.float32)
+        v = v_ref[sl].astype(jnp.float32)
+        lw = lw_ref[sl].astype(jnp.float32)
 
         cum = jnp.cumsum(lw, axis=0)                              # [BT, K]
         cum_tm1 = cum - lw
@@ -56,8 +56,7 @@ def _wkv_kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, y_ref, s_out_ref,
                          jnp.exp(cum_tm1[:, None, :] - cum[None, :, :]))
         att = jnp.where(mask, att, 0.0)
         y = y + att @ v
-        pl.store(y_ref, (0, pl.ds(ci * block_t, block_t), 0, slice(None)),
-                 y.astype(y_ref.dtype))
+        y_ref[sl] = y.astype(y_ref.dtype)
 
         # state update: state = diag(exp(cum_end)) state + sum_s dec_s k_s v_s^T
         dec_end = jnp.exp(cum[-1][None, :] - cum)                 # [BT, K]

@@ -158,21 +158,21 @@ def frame_knob_grid_ref(frames: jax.Array, prev: jax.Array, plan, *,
     mirroring the kernel's inputs.  Returns (payload [S, F, P, oh, ow]
     uint8, feats [S, F, 6] f32, changed [S, F] f32).
     """
-    from repro.kernels.frame_knobs import ARTIFACT_THRESH, _grid_compute
+    from repro.kernels.frame_knobs import (ARTIFACT_THRESH, _grid_compute,
+                                           _split_stats, exact_operators)
 
     if art_thresh is None:
         art_thresh = ARTIFACT_THRESH
     s = plan.bys.shape[0]
     f = frames.shape[0]
-    ry = jnp.asarray(plan.ry)
-    rx = jnp.asarray(plan.rx)
-    bys = jnp.asarray(plan.bys)
-    bxs = jnp.asarray(plan.bxs)
+    ry, rx, bys, bxs = map(jnp.asarray, exact_operators(plan))
+    frames = jnp.transpose(frames, (0, 3, 1, 2))          # planes-first
+    prev = jnp.transpose(prev, (0, 3, 1, 2))
     with_art = background is not None
     if plan.with_artifact and not with_art:
         raise ValueError("plan batches knob4 settings; pass background=")
     if with_art:
-        bg = jnp.asarray(background)
+        bg = jnp.transpose(jnp.asarray(background), (2, 0, 1))
         art_ids = jnp.asarray(plan.art_ids)
         enable = (jnp.ones((f,), jnp.int32) if art_enable is None
                   else jnp.asarray(art_enable, jnp.int32))
@@ -183,9 +183,12 @@ def frame_knob_grid_ref(frames: jax.Array, prev: jax.Array, plan, *,
         if with_art:
             kwargs = dict(bg=bg, art_mode=art_ids[si] * enable[fi],
                           art_thresh=art_thresh)
-        return _grid_compute(frames[fi], prev[fi], ry, rx, bys[si], bxs[si],
-                             cs=plan.cs, pixel_delta=pixel_delta, **kwargs)
+        payload, stats = _grid_compute(
+            frames[fi], prev[fi], ry, rx, bys[si], bxs[si], cs=plan.cs,
+            pixel_delta=pixel_delta, **kwargs)
+        return jnp.stack(payload), stats
 
-    payload, feats, changed = jax.lax.map(one, jnp.arange(s * f))
-    return (payload.reshape(s, f, plan.n_planes, plan.out_h, plan.out_w),
-            feats.reshape(s, f, -1), changed.reshape(s, f))
+    payload, stats = jax.lax.map(one, jnp.arange(s * f))
+    return _split_stats(
+        payload.reshape(s, f, plan.n_planes, plan.out_h, plan.out_w),
+        stats.reshape(s, f, 1, -1), plan.in_h * plan.in_w)

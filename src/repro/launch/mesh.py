@@ -12,6 +12,7 @@ for DP/FSDP; "model" is the intra-pod TP/SP axis (ICI-only collectives).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 __all__ = ["make_production_mesh", "make_mesh", "HardwareSpec", "V5E"]
 
@@ -40,9 +41,14 @@ V5E = HardwareSpec(
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...]):
-    """Arbitrary mesh for tests/benchmarks (e.g. (1, 1) on one CPU device)."""
-    return jax.make_mesh(shape, axes)
+    """Arbitrary mesh for tests/benchmarks (e.g. (1, 1) on one CPU device).
+
+    Axes are ``Auto``: the train step places values with
+    ``with_sharding_constraint``, which ``jax.make_mesh``'s default
+    ``Explicit`` axes reject."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))

@@ -24,6 +24,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_config
 from repro.models.registry import DECODE_SLACK, build_model, make_batch
 
@@ -81,6 +82,7 @@ def serve(arch: str, *, batch: int = 4, prompt_len: int = 64, gen: int = 32,
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--batch", type=int, default=4)

@@ -32,10 +32,12 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.checkpoint.checkpointer import Checkpointer
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_config
 from repro.configs.base import ShapeCell
 from repro.core.approx_comm import make_grad_compressor
 from repro.data.pipeline import Prefetcher, TokenStream
+from repro.launch.mesh import make_mesh
 from repro.launch.steps import build_train_step
 from repro.models.registry import build_model, make_batch
 from repro.optim.adamw import AdamWConfig, init_opt_state
@@ -76,7 +78,7 @@ def train(arch: str, *, steps: int = 50, batch: int = 8, seq: int = 128,
     else:
         axes = ("data", "model") if len(mesh_shape) == 2 else (
             "pod", "data", "model")
-    mesh = jax.make_mesh(mesh_shape, axes)
+    mesh = make_mesh(mesh_shape, axes)
     cell = ShapeCell("custom", seq, batch, "train")
 
     compressor = (make_grad_compressor(grad_bits, min_size=1024)
@@ -167,6 +169,7 @@ def train(arch: str, *, steps: int = 50, batch: int = 8, seq: int = 128,
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--steps", type=int, default=50)

@@ -331,10 +331,9 @@ def shard_fleet_tick(fn, mesh: Mesh):
     fully independent -- no collectives -- so sharding is pure data
     parallelism and cannot change numerics.
     """
-    from jax.experimental.shard_map import shard_map
     spec = P("cams")
-    return shard_map(fn, mesh=mesh, in_specs=(spec,) * 8, out_specs=spec,
-                     check_rep=False)
+    return jax.shard_map(fn, mesh=mesh, in_specs=(spec,) * 8,
+                         out_specs=spec, check_vma=False)
 
 
 def logical_to_sharding(specs: Any, mesh: Mesh):

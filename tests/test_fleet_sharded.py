@@ -107,6 +107,7 @@ print("PARITY_OK")
 
 def test_eight_device_mesh_parity_in_subprocess():
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"          # a virtual CPU mesh, never a chip
     env["XLA_FLAGS"] = ("--xla_force_host_platform_device_count=8 "
                         + env.get("XLA_FLAGS", ""))
     env["PYTHONPATH"] = os.pathsep.join(

@@ -224,6 +224,40 @@ class TestFrameKnobGrid:
                 assert got == want
 
 
+class TestFrameKnobGridExactRounding:
+    """The grid kernel's arithmetic is exact, so its rounding matches exact
+    rational rounding on every backend: checked here against Python
+    integers and float64 (exact at these magnitudes), ties included."""
+
+    def test_round_div_is_half_even(self):
+        from repro.kernels.frame_knobs import _round_div
+
+        nums = np.arange(-3000, 3001, dtype=np.int64) * 37
+        for den in (2, 4, 25, 100, 225, 1000):
+            got = np.asarray(_round_div(jnp.asarray(nums, jnp.int32), den))
+            q, r = np.divmod(nums, den)             # floor division
+            up = (2 * r > den) | ((2 * r == den) & (q % 2 == 1))
+            np.testing.assert_array_equal(got, q + up)
+
+    @pytest.mark.parametrize("res", range(5))
+    def test_resize_matches_exact_rounding(self, res):
+        from repro.core import knobs as K
+        from repro.kernels.frame_knobs import (_resize, build_transform_plan,
+                                               exact_operators)
+
+        plan = build_transform_plan(40, 56, scale=K.RESOLUTION_SCALES[res],
+                                    cs=0, blur_ks=(0,))
+        ry, rx, _, _ = exact_operators(plan)
+        rng = np.random.default_rng(res)
+        plane = rng.integers(0, 255, (40, 56)).astype(np.float64)
+        plane[:, 1::2] = plane[:, ::2] + 1      # neighbours 1 apart: ties
+        want = np.round((ry.astype(np.float64) @ plane)
+                        @ rx.astype(np.float64).T)
+        got = np.asarray(_resize(jnp.asarray(plane, jnp.float32),
+                                 jnp.asarray(ry), jnp.asarray(rx)))
+        np.testing.assert_array_equal(got, want)
+
+
 class TestFrameKnobGridArtifact:
     """knob4 (artifact removal / background subtraction) as a device-side
     per-setting operator: interpret-mode kernel vs ``frame_knob_grid_ref``

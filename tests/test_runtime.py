@@ -12,6 +12,7 @@ from repro.core.approx_comm import (LEVELS, _quant_roundtrip,
                                     characterize_fidelity, compressed_mean,
                                     make_grad_compressor)
 from repro.data.pipeline import BackupFetcher, Prefetcher, TokenStream
+from repro.launch.mesh import make_mesh
 from repro.optim.adamw import AdamWConfig, adamw_update, init_opt_state
 
 
@@ -85,7 +86,7 @@ class TestCheckpointer:
         from jax.sharding import NamedSharding, PartitionSpec as P
         ck = Checkpointer(str(tmp_path))
         ck.save(3, self._tree(3.0))
-        mesh = jax.make_mesh((1, 1), ("data", "model"))
+        mesh = make_mesh((1, 1), ("data", "model"))
         sh = {"a": {"w": NamedSharding(mesh, P("data", "model"))},
               "b": NamedSharding(mesh, P(None))}
         restored, _ = ck.restore(self._tree(), shardings=sh)
@@ -149,14 +150,13 @@ class TestApproxComm:
         assert fid[16] >= fid[8] >= fid[4] > 0.95
 
     def test_compressed_mean_matches_pmean(self):
-        from jax.sharding import Mesh, PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
-        mesh = jax.make_mesh((1,), ("pod",))
+        from jax.sharding import PartitionSpec as P
+        mesh = make_mesh((1,), ("pod",))
         x = jax.random.normal(jax.random.PRNGKey(2), (256, 512))
 
-        f = shard_map(lambda v: compressed_mean(v, "pod", 8),
-                      mesh=mesh, in_specs=P(), out_specs=P(),
-                      check_rep=False)
+        f = jax.shard_map(lambda v: compressed_mean(v, "pod", 8),
+                          mesh=mesh, in_specs=P(), out_specs=P(),
+                          check_vma=False)
         out = f(x)
         exact = x  # single member mean = itself (up to quantization)
         assert float(jnp.abs(out - exact).max() /
