@@ -31,6 +31,7 @@ from collections import OrderedDict
 from typing import Iterator, Sequence
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core.api import (AdmissionRejected, BoundedEventBuffer, BrokerDown,
                             CameraQosResult, DeliveredFrame, EventKind,
@@ -201,7 +202,6 @@ class CamBroker:
         # update_qos fanning out over subscriptions sharing this camera)
         # is a no-op instead of a redundant grid sweep
         self._rechar_memo: tuple | None = None
-        self.payload_cache_hits = 0
         self.infeasible_reported = 0
         self.prescreen_evals = 0
         self.prescreen_stepdowns = 0
@@ -507,9 +507,11 @@ class CamBroker:
             if entry[1] is not None:
                 est = float(entry[1])       # exact deflate already known
             else:
-                feats = FK.proxy_features_host(payload)
-                est = float(proxy.predict(setting.colorspace, payload.nbytes,
-                                          feats, art=setting.artifact > 0))
+                with TraceAnnotation("mez.prescreen"):
+                    feats = FK.proxy_features_host(payload)
+                    est = float(proxy.predict(setting.colorspace,
+                                              payload.nbytes, feats,
+                                              art=setting.artifact > 0))
             # stop on a fitting candidate, or ship the last-evaluated one
             # (never step to a setting we won't evaluate: the returned
             # entry must be the returned setting's payload)
@@ -538,15 +540,15 @@ class CamBroker:
         else:
             entry = self._payload_cache.get(key)
         if entry is not None:
-            self.payload_cache_hits += 1
             return entry
-        out = frame
-        mode = K.ARTIFACT_MODES[setting.artifact]
-        if mode != "off":
-            bg = (self.background if self.background is not None
-                  else np.zeros_like(frame))
-            out = K._artifact_removal(out, bg, mode)
-        out = K.transform_frame(out, setting)
+        with TraceAnnotation("mez.transform"):
+            out = frame
+            mode = K.ARTIFACT_MODES[setting.artifact]
+            if mode != "off":
+                bg = (self.background if self.background is not None
+                      else np.zeros_like(frame))
+                out = K._artifact_removal(out, bg, mode)
+            out = K.transform_frame(out, setting)
         entry = [out, None]
         if self.shared_cache is not None:
             self.shared_cache.put((self.camera_id,) + key, entry)
@@ -582,7 +584,8 @@ class CamBroker:
         if entry is None:
             entry = self._transform_cached(ts, frame, setting)
         if entry[1] is None:
-            entry[1] = wire_size(entry[0])
+            with TraceAnnotation("mez.deflate"):
+                entry[1] = wire_size(entry[0])
         return K.KnobResult(entry[0], entry[1], setting.overhead_ms)
 
     def drain_activity(self) -> list[float]:
@@ -1221,58 +1224,59 @@ class EdgeBroker:
         when every camera has failed does poll raise ``RPCTimeout``.
         An empty batch means the subscription is drained (or closed).
         """
-        if self.crashed:
-            raise RPCTimeout("EdgeBroker down")
-        rec = self._subscriptions.get(subscription_id)
-        if rec is None:
-            return FrameBatch((), subscription_id)
-        fleet = self._ensure_fleet(rec) if rec.controlled else None
-        self._apply_pending_refreshes(rec)
-        t0 = time.monotonic()
-        active = self._active_order(rec)
-        out: list[DeliveredFrame] = []
-        decisions = None
-        if fleet is not None and (active or rec.drift is not None):
-            # ONE fused compiled dispatch per poll: the controller step for
-            # every serving camera, the drift-monitor tick on the residuals
-            # aggregated at the END of the previous poll, and the
-            # decision->knob-code application, in a single jitted (and,
-            # with a mesh, camera-sharded) call.  Fired drift lanes
-            # re-characterize on the host and the SAME compiled tick
-            # re-decides against the fresh tables -- so the host side does
-            # I/O and bookkeeping only.  Note the tick covers every serving
-            # camera even when a saturated ``max_frames`` ends the fetch
-            # loop early; with the default share/credit sizing every camera
-            # is fetched each poll and fused decisions match the host path
-            # exactly.
-            decisions = self._fleet_tick(rec, fleet, active)
-        if active:
-            k = rec.rr_offset % len(active)
-            rec.rr_offset += 1
-            order = active[k:] + active[:k]
-            share = max(1, max_frames // len(order))
-            for cid in order:
-                if len(out) >= max_frames:
-                    break
-                # the deadline never forges an end-of-stream: an empty batch
-                # must mean drained, so expiry only stops a poll that has
-                # already made progress
-                if (out and deadline is not None
-                        and time.monotonic() - t0 > deadline):
-                    break
-                self._fetch_into(rec, cid, min(share, max_frames - len(out)),
-                                 out,
-                                 decision=(decisions.get(cid)
-                                           if decisions is not None else None))
-        out.sort(key=lambda d: (d.timestamp, d.camera_id))
-        self._drift_tick(rec, out, fused=fleet is not None)
-        if not out:
-            cams = rec.cameras.values()
-            if any(c.failed for c in cams) and all(
-                    c.failed or c.detached for c in cams):
-                raise RPCTimeout(
-                    f"all cameras of {subscription_id} unreachable")
-        return FrameBatch(tuple(out), subscription_id)
+        with TraceAnnotation("mez.poll"):
+            if self.crashed:
+                raise RPCTimeout("EdgeBroker down")
+            rec = self._subscriptions.get(subscription_id)
+            if rec is None:
+                return FrameBatch((), subscription_id)
+            fleet = self._ensure_fleet(rec) if rec.controlled else None
+            self._apply_pending_refreshes(rec)
+            t0 = time.monotonic()
+            active = self._active_order(rec)
+            out: list[DeliveredFrame] = []
+            decisions = None
+            if fleet is not None and (active or rec.drift is not None):
+                # ONE fused compiled dispatch per poll: the controller step
+                # for every serving camera, the drift-monitor tick on the
+                # residuals aggregated at the END of the previous poll, and
+                # the decision->knob-code application, in a single jitted
+                # (and, with a mesh, camera-sharded) call.  Fired drift
+                # lanes re-characterize on the host and the SAME compiled
+                # tick re-decides against the fresh tables -- so the host
+                # side does I/O and bookkeeping only.  Note the tick covers
+                # every serving camera even when a saturated ``max_frames``
+                # ends the fetch loop early; with the default share/credit
+                # sizing every camera is fetched each poll and fused
+                # decisions match the host path exactly.
+                decisions = self._fleet_tick(rec, fleet, active)
+            if active:
+                k = rec.rr_offset % len(active)
+                rec.rr_offset += 1
+                order = active[k:] + active[:k]
+                share = max(1, max_frames // len(order))
+                for cid in order:
+                    if len(out) >= max_frames:
+                        break
+                    # the deadline never forges an end-of-stream: an empty
+                    # batch must mean drained, so expiry only stops a poll
+                    # that has already made progress
+                    if (out and deadline is not None
+                            and time.monotonic() - t0 > deadline):
+                        break
+                    self._fetch_into(
+                        rec, cid, min(share, max_frames - len(out)), out,
+                        decision=(decisions.get(cid)
+                                  if decisions is not None else None))
+            out.sort(key=lambda d: (d.timestamp, d.camera_id))
+            self._drift_tick(rec, out, fused=fleet is not None)
+            if not out:
+                cams = rec.cameras.values()
+                if any(c.failed for c in cams) and all(
+                        c.failed or c.detached for c in cams):
+                    raise RPCTimeout(
+                        f"all cameras of {subscription_id} unreachable")
+            return FrameBatch(tuple(out), subscription_id)
 
     # mezlint: poll-path
     def _fleet_tick(self, rec: _Subscription, fleet: FleetController,
@@ -1283,27 +1287,29 @@ class EdgeBroker:
         cameras hold, exactly as the host path never consults their
         controller), hand last poll's drift residuals to the tick, and
         route fired lanes through recharacterize + ``retick``."""
-        valid = np.zeros(fleet.n_lanes, bool)
-        for cid in active:
-            cam = self._cams.get(cid)
-            if cam is None or cam.crashed:
-                continue
-            lane = fleet.lane_of[cid]
-            valid[lane] = rec.lat_valid[lane]
-        errs = dvalid = None
-        if rec.drift_pending is not None:
-            errs, dvalid = rec.drift_pending
-            rec.drift_pending = None
-        # an all-drained poll still ticks when drift is armed (the monitor
-        # observes every poll, fused or not) but records no history row --
-        # the unfused path never decided on empty polls either
-        result = fleet.tick(rec.lat_lane, valid, errs, dvalid,
-                            record=bool(active))
-        if result.fired_cams:
-            self._refresh_cameras(rec, result.fired_cams)
-            if active:
-                result = fleet.retick()
-        return result
+        with TraceAnnotation("mez.fleet_tick"):
+            valid = np.zeros(fleet.n_lanes, bool)
+            for cid in active:
+                cam = self._cams.get(cid)
+                if cam is None or cam.crashed:
+                    continue
+                lane = fleet.lane_of[cid]
+                valid[lane] = rec.lat_valid[lane]
+            errs = dvalid = None
+            if rec.drift_pending is not None:
+                errs, dvalid = rec.drift_pending
+                rec.drift_pending = None
+            # an all-drained poll still ticks when drift is armed (the
+            # monitor observes every poll, fused or not) but records no
+            # history row -- the unfused path never decided on empty polls
+            # either
+            result = fleet.tick(rec.lat_lane, valid, errs, dvalid,
+                                record=bool(active))
+            if result.fired_cams:
+                self._refresh_cameras(rec, result.fired_cams)
+                if active:
+                    result = fleet.retick()
+            return result
 
     def _drift_tick(self, rec: _Subscription,
                     frames: list[DeliveredFrame], *,
@@ -1409,26 +1415,27 @@ class EdgeBroker:
         emitting one TABLE_REFRESH event per lane either way.  Shared by
         the host queue (``_apply_pending_refreshes``) and the fused tick's
         fire-set (``_fleet_tick``)."""
-        for cid in fired:
-            cam = self._cams.get(cid)
-            cur = rec.cameras.get(cid)
-            at = cur.cursor if cur is not None else 0.0
-            if cam is None or cam.crashed:
+        with TraceAnnotation("mez.drift_refresh"):
+            for cid in fired:
+                cam = self._cams.get(cid)
+                cur = rec.cameras.get(cid)
+                at = cur.cursor if cur is not None else 0.0
+                if cam is None or cam.crashed:
+                    rec.events.append(SessionEvent(
+                        EventKind.TABLE_REFRESH, cid, rec.sub_id, at,
+                        "drift: camera unreachable; stale tables kept"))
+                    continue
+                try:
+                    refreshed = cam.recharacterize()
+                except BrokerDown:
+                    rec.events.append(SessionEvent(
+                        EventKind.TABLE_REFRESH, cid, rec.sub_id, at,
+                        "drift: camera unreachable; stale tables kept"))
+                    continue
                 rec.events.append(SessionEvent(
                     EventKind.TABLE_REFRESH, cid, rec.sub_id, at,
-                    "drift: camera unreachable; stale tables kept"))
-                continue
-            try:
-                refreshed = cam.recharacterize()
-            except BrokerDown:
-                rec.events.append(SessionEvent(
-                    EventKind.TABLE_REFRESH, cid, rec.sub_id, at,
-                    "drift: camera unreachable; stale tables kept"))
-                continue
-            rec.events.append(SessionEvent(
-                EventKind.TABLE_REFRESH, cid, rec.sub_id, at,
-                "drift: tables re-swept from live frames" if refreshed
-                else "drift: re-sweep unavailable; stale tables kept"))
+                    "drift: tables re-swept from live frames" if refreshed
+                    else "drift: re-sweep unavailable; stale tables kept"))
 
     def _fetch_into(self, rec: _Subscription, camera_id: str, budget: int,
                     out: list[DeliveredFrame], *,
@@ -1460,12 +1467,13 @@ class EdgeBroker:
         cur.credits_held += budget
         rec.credits_granted += budget
         try:
-            frames = cam.fetch(cur.cursor, cur.spec.t_stop,
-                               latency_feedback=feedback,
-                               controlled=rec.controlled,
-                               max_frames=budget,
-                               decision=decision,
-                               budget_scale=rec.budget_scale)
+            with TraceAnnotation("mez.fetch"):
+                frames = cam.fetch(cur.cursor, cur.spec.t_stop,
+                                   latency_feedback=feedback,
+                                   controlled=rec.controlled,
+                                   max_frames=budget,
+                                   decision=decision,
+                                   budget_scale=rec.budget_scale)
         except BrokerDown as e:
             cur.failed = True
             rec.invalidate_active()

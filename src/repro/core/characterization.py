@@ -267,13 +267,17 @@ def characterize(camera_factory, *, clip_len: int = 24,
                 f"background (4:2:0-subsample-able planes); got shape "
                 f"{bg.shape}.  Use engine='reference' for odd geometries, "
                 f"or engine='auto' to fall back automatically.")
+        from jax.profiler import TraceAnnotation
+
         from repro.core import grid_engine
-        grid = grid_engine.run_grid(bg, [f for _, f, _ in clip],
-                                    detector_thresh=detector_thresh,
-                                    include_artifact=include_artifact)
-        return table_from_grid(grid, [gt for _, _, gt in clip],
-                               min_accuracy=min_accuracy,
-                               include_artifact=include_artifact)
+        with TraceAnnotation("mez.char"):
+            grid = grid_engine.run_grid(bg, [f for _, f, _ in clip],
+                                        detector_thresh=detector_thresh,
+                                        include_artifact=include_artifact)
+            with TraceAnnotation("mez.char.score"):
+                return table_from_grid(grid, [gt for _, _, gt in clip],
+                                       min_accuracy=min_accuracy,
+                                       include_artifact=include_artifact)
     elif engine == "reference":
         settings, sizes, accs, residuals = _sweep_reference(
             bg, clip, include_artifact=include_artifact,

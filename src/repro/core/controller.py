@@ -43,6 +43,7 @@ from collections.abc import Mapping
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core.characterization import CharacterizationTable, LatencyRegression
 from repro.core.drift import (DriftConfig, DriftParams, DriftState,
@@ -990,7 +991,8 @@ class FleetController:
              self._drift_params) = operands
         new_ctrl, new_drift, aux = self._tick_jit(*operands)
         self.state = new_ctrl
-        aux = jax.device_get(aux)
+        with TraceAnnotation("mez.fleet_tick.wait"):
+            aux = jax.device_get(aux)
         fired_cams: list[str] = []
         if self._drift is not None:
             fired_cams = self._drift.absorb_fused(

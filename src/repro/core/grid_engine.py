@@ -47,6 +47,7 @@ import zlib
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core import detector as det
 from repro.core import knobs as K
@@ -456,58 +457,64 @@ def run_grid(background: np.ndarray, frames: list[np.ndarray], *,
         if gi + lookahead < len(todo):
             in_flight[gi % lookahead] = dispatch(todo[gi + lookahead])
         plan_of_cs[(res, cs)] = plan
-        diff_np = np.asarray(diff[:, :n_clip])           # [S, F, gh, gw]
-        feats_np = np.asarray(feats[:, 1:n_real])        # [S, F, 6]
-        s_dim, f_dim = diff_np.shape[:2]
-        # only the calibration frame of each (blur, artifact) setting ever
-        # needs its payload on the host -- slice on device, don't ship the
-        # batch
-        cal_idx = np.asarray([1 + (res * s_dim + b) % n_clip
-                              for b in range(s_dim)])
-        cal_payloads = np.asarray(payload[jnp.arange(s_dim),
-                                          jnp.asarray(cal_idx)])
+        with TraceAnnotation("mez.char.wait"):
+            diff_np = np.asarray(diff[:, :n_clip])       # [S, F, gh, gw]
+            feats_np = np.asarray(feats[:, 1:n_real])    # [S, F, 6]
+            s_dim, f_dim = diff_np.shape[:2]
+            # only the calibration frame of each (blur, artifact) setting
+            # ever needs its payload on the host -- slice on device, don't
+            # ship the batch
+            cal_idx = np.asarray([1 + (res * s_dim + b) % n_clip
+                                  for b in range(s_dim)])
+            cal_payloads = np.asarray(payload[jnp.arange(s_dim),
+                                              jnp.asarray(cal_idx)])
 
         # adaptive threshold: detector.detect's own helper, batched, one
         # introselect pass for both quantiles (NumPy beats XLA's sort here)
         gh, gw = diff_np.shape[2:]
-        eff = det.adaptive_threshold(
-            diff_np.reshape(s_dim, f_dim, -1), detector_thresh, axis=-1)
+        with TraceAnnotation("mez.char.boxes"):
+            eff = det.adaptive_threshold(
+                diff_np.reshape(s_dim, f_dim, -1), detector_thresh, axis=-1)
 
-        label_on_device = use_pallas
-        if not label_on_device:
-            try:
-                mask = det.dilate_cross(diff_np > eff[:, :, None, None])
-                ids, bg_label = _label_host(mask.reshape(-1, gh, gw))
-            except ImportError:             # no scipy: device labeler works
-                label_on_device = True
-        if label_on_device:
-            ids = np.asarray(_label_group(jnp.asarray(diff_np),
-                                          jnp.asarray(eff)))
-            ids = ids.reshape(s_dim * f_dim, gh, gw)
-            bg_label = gh * gw
+        with TraceAnnotation("mez.char.label"):
+            label_on_device = use_pallas
+            if not label_on_device:
+                try:
+                    mask = det.dilate_cross(diff_np > eff[:, :, None, None])
+                    ids, bg_label = _label_host(mask.reshape(-1, gh, gw))
+                except ImportError:         # no scipy: device labeler works
+                    label_on_device = True
+            if label_on_device:
+                ids = np.asarray(_label_group(jnp.asarray(diff_np),
+                                              jnp.asarray(eff)))
+                ids = ids.reshape(s_dim * f_dim, gh, gw)
+                bg_label = gh * gw
 
         sy, sx = h / gh, w / gw
         min_px = max(2.0, min_area / (sy * sx))
-        boxes = _segment_boxes_batch(ids, diff_np.reshape(-1, gh, gw),
-                                     background_label=bg_label,
-                                     sy=sy, sx=sx, min_px=min_px)
-        for s_i in range(s_dim):
-            art, b = int(plan.art_ids[s_i]), s_i % n_blur
-            combo = (res, cs, b, art)
-            feats_all[combo] = feats_np[s_i]
-            dets[combo] = boxes[s_i * f_dim:s_i * f_dim + n_clip]
-            wire = _wire_payload(cal_payloads[s_i], cs)
-            cal_samples.append((cs, art, plan.payload_bytes,
-                                feats_np[s_i, cal_idx[s_i] - 1],
-                                len(zlib.compress(wire.tobytes(), 1))))
+        with TraceAnnotation("mez.char.boxes"):
+            boxes = _segment_boxes_batch(ids, diff_np.reshape(-1, gh, gw),
+                                         background_label=bg_label,
+                                         sy=sy, sx=sx, min_px=min_px)
+        with TraceAnnotation("mez.char.calib"):
+            for s_i in range(s_dim):
+                art, b = int(plan.art_ids[s_i]), s_i % n_blur
+                combo = (res, cs, b, art)
+                feats_all[combo] = feats_np[s_i]
+                dets[combo] = boxes[s_i * f_dim:s_i * f_dim + n_clip]
+                wire = _wire_payload(cal_payloads[s_i], cs)
+                cal_samples.append((cs, art, plan.payload_bytes,
+                                    feats_np[s_i, cal_idx[s_i] - 1],
+                                    len(zlib.compress(wire.tobytes(), 1))))
 
-    proxy = _fit_proxy(cal_samples)
-    sizes = {
-        (res, cs, b, art): proxy.predict(
-            cs, plan_of_cs[(res, cs)].payload_bytes,
-            feats_all[(res, cs, b, art)], art=art > 0)
-        for (res, cs, b, art) in feats_all
-    }
+    with TraceAnnotation("mez.char.score"):
+        proxy = _fit_proxy(cal_samples)
+        sizes = {
+            (res, cs, b, art): proxy.predict(
+                cs, plan_of_cs[(res, cs)].payload_bytes,
+                feats_all[(res, cs, b, art)], art=art > 0)
+            for (res, cs, b, art) in feats_all
+        }
     return GridCharacterization(
         combos=tuple(sorted(feats_all)), dets=dets, sizes=sizes,
         change_counts=np.asarray(change_counts_dev), pixels=h * w,
@@ -544,12 +551,14 @@ def refresh_tables(background: np.ndarray, frames: list[np.ndarray], *,
     from repro.core import characterization as C
     from repro.core.controller import JaxControllerTables
 
-    grid = run_grid(background, frames, detector_thresh=detector_thresh,
-                    include_artifact=include_artifact)
-    if gts is None:
-        gts = grid.dets[(0, 0, 0, 0)]
-    table = C.table_from_grid(grid, gts, min_accuracy=min_accuracy,
-                              include_artifact=include_artifact)
+    with TraceAnnotation("mez.char"):
+        grid = run_grid(background, frames, detector_thresh=detector_thresh,
+                        include_artifact=include_artifact)
+        if gts is None:
+            gts = grid.dets[(0, 0, 0, 0)]
+        with TraceAnnotation("mez.char.score"):
+            table = C.table_from_grid(grid, gts, min_accuracy=min_accuracy,
+                                      include_artifact=include_artifact)
     # provenance: these tables were swept from live frames, not the
     # offline calibration campaign (drift tests / fig12 assert on this)
     table.source = "online-refresh"

@@ -483,10 +483,10 @@ class TestTransformMemoAndBroker:
         # latency_feedback=None -> the controller's current setting is used
         # verbatim for both walks (no PI update between them)
         a = cam.fetch(0.0, 10.0)
-        hits_before = cam.payload_cache_hits
+        hits_before = cam.shared_cache.hits
         b = cam.fetch(0.0, 10.0)
         # second fetch walked the same frames at the same knob setting
-        assert cam.payload_cache_hits > hits_before
+        assert cam.shared_cache.hits > hits_before
         for da, db in zip(a, b):
             if da.frame is not None and db.frame is not None:
                 np.testing.assert_array_equal(da.frame, db.frame)
