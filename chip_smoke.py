@@ -11,7 +11,8 @@ characterization clip, a 100 ms / 0.95 target):
                 ``frame_knob_grid`` kernel, compiled by Mosaic, and the
                 device labeler -- plus one (resolution, colorspace) group
                 re-run and compared bit for bit with
-                ``kernels.ref.frame_knob_grid_ref`` on the CPU device,
+                ``kernels.ref.frame_knob_grid_ref`` on the CPU device;
+                it prints the labeler's scan rounds of each group,
   session       a 5-camera ``MezClient`` session subscribed with
                 ``SubscriptionOptions(fleet=True)``, so the fused fleet
                 tick runs on the chip, drained and compared frame for
@@ -21,9 +22,9 @@ With ``--four-chips`` it runs only the mesh-sharded fleet tick: a
 ``fleet_mesh(4)`` tick over 4096 lanes against the same tick on one
 device, decisions byte-identical, lanes spread over all four devices.
 
-Each phase prints one line; the last line of standard output is one JSON
-object, ``{"ok": true, "device": {...}}``.  A failed phase raises, so the
-script exits non-zero and prints no such line.
+Each phase prints one line (characterize two); the last line of standard
+output is one JSON object, ``{"ok": true, "device": {...}}``.  A failed
+phase raises, so the script exits non-zero and prints no such line.
 
     python chip_smoke.py [--four-chips]
 """
@@ -85,10 +86,25 @@ def phase_characterize():
             dynamics="complex", seed=EDGE.seed, height=EDGE.frame_height,
             width=EDGE.frame_width))
 
-    t0 = time.perf_counter()
-    table = characterize(factory, clip_len=EDGE.characterization_clip,
-                         include_artifact=True, engine="batched")
-    sweep_s = time.perf_counter() - t0
+    # keep each group's labeler inputs, to count its rounds after the sweep
+    label, inputs = GE._label_group, []
+
+    def kept(diff, eff):
+        inputs.append((diff, eff))
+        return label(diff, eff)
+
+    GE._label_group = kept
+    try:
+        t0 = time.perf_counter()
+        table = characterize(factory, clip_len=EDGE.characterization_clip,
+                             include_artifact=True, engine="batched")
+        sweep_s = time.perf_counter() - t0
+    finally:
+        GE._label_group = label
+    rounds = [f"{d.shape[2]}x{d.shape[3]}:{GE.label_rounds(d, e)}"
+              for d, e in inputs]
+    print(f"[labeler] scan rounds of each of the sweep's {len(inputs)} "
+          f"groups: {' '.join(rounds)}", flush=True)
     n_art = sum(s.artifact > 0 for s in table.settings)
     grid_size = (len(K.RESOLUTION_SCALES) * len(K.COLORSPACES)
                  * len(K.BLUR_KERNELS) * len(K.ARTIFACT_MODES)

@@ -23,8 +23,9 @@ renegotiation (CANS-style online self-configuration):
   3. **Detector scoring** -- background diff and the proxy features run
      batched over the settings dimension on device; thresholding, dilation,
      and component labeling run vectorized over the ``[settings, frames]``
-     batch (scipy's C labeling on CPU; the pointer-jumping min-propagation
-     kernel ``_label_group`` on TPU, where host round-trips are the enemy).
+     batch (scipy's C labeling on CPU; on TPU, where host round-trips are
+     the enemy, ``_label_group``: rounds of gather-free segmented min scans
+     along rows and columns, run to their fixpoint).
      Box extraction is segment-vectorized per frame (lexsort + reduceat),
      semantically identical to ``detector.boxes_from_labels``.  The
      adaptive threshold's median/percentile use NumPy's introselect (XLA's
@@ -54,7 +55,7 @@ from repro.core import knobs as K
 from repro.kernels import frame_knobs as FK
 
 __all__ = ["GridCharacterization", "WireSizeProxy", "run_grid",
-           "stage_clip", "refresh_tables", "PIXEL_DELTA"]
+           "stage_clip", "refresh_tables", "label_rounds", "PIXEL_DELTA"]
 
 PIXEL_DELTA = 8.0        # knobs.frame_difference's noise-robust change delta
 _FRAME_BUCKET = 16       # frame-axis padding so jit caches are shared
@@ -138,15 +139,9 @@ def _payload_diff(payload: jax.Array):
     return jnp.abs(gray[:, 1:] - gray[:, :1])
 
 
-@jax.jit
-def _label_group(diff: jax.Array, eff: jax.Array) -> jax.Array:
-    """Threshold -> cross dilation -> 4-connected components, batched.
-
-    Labels are min-flat-index per component (the same fixpoint as
-    ``detector._label``); background pixels carry the ``gh*gw`` sentinel.
-    Pointer jumping (label indirection) accelerates min-propagation from
-    O(component diameter) to O(log diameter) rounds.
-    """
+def _foreground(diff: jax.Array, eff: jax.Array) -> jax.Array:
+    """Threshold -> cross dilation of a [s, f, gh, gw] diff batch, as one
+    [s*f, gh, gw] bool batch (``detector.dilate_cross``'s semantics)."""
     s, f, gh, gw = diff.shape
     mask = diff > eff[:, :, None, None]
     fr = jnp.zeros_like(mask[:, :, :1, :])
@@ -156,38 +151,85 @@ def _label_group(diff: jax.Array, eff: jax.Array) -> jax.Array:
     m = m | jnp.concatenate([mask[:, :, 1:, :], fr], axis=2)
     m = m | jnp.concatenate([fc, mask[:, :, :, :-1]], axis=3)
     m = m | jnp.concatenate([mask[:, :, :, 1:], fc], axis=3)
+    return m.reshape(s * f, gh, gw)
 
+
+def _run_offsets(fg: jax.Array, axis: int, stride: int) -> jax.Array:
+    """``stride`` x the 1-based index of each pixel's run of foreground
+    along ``axis`` (a background pixel carries the last run before it)."""
+    before = jax.lax.slice_in_dim(fg, 0, fg.shape[axis] - 1, axis=axis)
+    before = jnp.concatenate(
+        [jnp.zeros_like(jax.lax.slice_in_dim(fg, 0, 1, axis=axis)), before],
+        axis=axis)
+    return jnp.cumsum(fg & ~before, axis=axis, dtype=jnp.int32) * stride
+
+
+def _label_fixpoint(fg: jax.Array) -> tuple[jax.Array, jax.Array]:
+    """4-connected components of a [B, gh, gw] bool batch by segmented min
+    scans: ``(ids, rounds)``, ids the min flat index of each pixel's
+    component (``gh*gw`` on background), rounds the scan rounds run.
+
+    One round: every foreground pixel takes the min id of its run of
+    foreground along its row (a forward then a backward scan), then along
+    its column.  Two 4-adjacent foreground pixels share a row run or a
+    column run and a round only lowers ids within a component, so the
+    fixpoint is each component's min flat index.  A run is made a segment
+    of a plain cumulative min by offsetting ids with ``(gh*gw + 1)`` x the
+    run's index: an earlier run (forward) or a later one (backward) then
+    never wins, and no gather is needed.
+    """
+    _, gh, gw = fg.shape
     big = gh * gw
-    iota = jnp.arange(big, dtype=jnp.int32).reshape(gh, gw)
-    mm = m.reshape(s * f, gh, gw)
-    ids0 = jnp.where(mm, iota[None], big)
-    big_row = jnp.full((s * f, 1, gw), big, jnp.int32)
-    big_col = jnp.full((s * f, gh, 1), big, jnp.int32)
-    pad_tail = jnp.full((s * f, 1), big, jnp.int32)
+    ids0 = jnp.where(fg, jnp.arange(big, dtype=jnp.int32).reshape(gh, gw),
+                     big)
+    offsets = [(axis, _run_offsets(fg, axis, big + 1)) for axis in (2, 1)]
 
-    def prop(ids):
-        up = jnp.concatenate([big_row, ids[:, :-1, :]], axis=1)
-        down = jnp.concatenate([ids[:, 1:, :], big_row], axis=1)
-        left = jnp.concatenate([big_col, ids[:, :, :-1]], axis=2)
-        right = jnp.concatenate([ids[:, :, 1:], big_col], axis=2)
-        n = jnp.minimum(jnp.minimum(jnp.minimum(ids, up), down),
-                        jnp.minimum(left, right))
-        n = jnp.where(mm, n, big)
-        flat = jnp.concatenate([n.reshape(s * f, -1), pad_tail], axis=1)
-        jumped = jnp.take_along_axis(
-            flat, n.reshape(s * f, -1), axis=1).reshape(n.shape)
-        return jnp.where(mm, jnp.minimum(n, jumped), big)
+    def step(ids):
+        for axis, off in offsets:
+            ids = jnp.where(fg, jax.lax.cummin(ids - off, axis=axis) + off,
+                            big)
+            ids = jnp.where(fg, jax.lax.cummin(ids + off, axis=axis,
+                                               reverse=True) - off, big)
+        return ids
 
     def cond(carry):
-        ids, prev = carry
+        ids, prev, _ = carry
         return jnp.any(ids != prev)
 
     def body(carry):
-        ids, _ = carry
-        return prop(ids), ids
+        ids, _, rounds = carry
+        return step(ids), ids, rounds + 1
 
-    ids, _ = jax.lax.while_loop(cond, body, (prop(ids0), ids0))
-    return ids.reshape(s, f, gh, gw)
+    ids, _, rounds = jax.lax.while_loop(
+        cond, body, (step(ids0), ids0, jnp.int32(1)))
+    return ids, rounds
+
+
+@jax.jit
+def _label_group(diff: jax.Array, eff: jax.Array) -> jax.Array:
+    """Threshold -> cross dilation -> 4-connected components, batched.
+
+    Labels are min-flat-index per component (the same fixpoint as
+    ``detector._label``); background pixels carry the ``gh*gw`` sentinel.
+    Each round is a forward and a backward segmented min scan along every
+    row, then along every column (``_label_fixpoint``): streamed cumulative
+    ops, no gather, and a handful of rounds where four-neighbour
+    propagation takes one per pixel of a component's diameter.
+    """
+    ids, _ = _label_fixpoint(_foreground(diff, eff))
+    return ids.reshape(diff.shape)
+
+
+def label_rounds(diff, eff) -> int:
+    """The scan rounds ``_label_group``'s fixpoint takes on ``(diff, eff)``
+    (the largest over the batch, the last round, which changes nothing,
+    included)."""
+    return int(_label_rounds(jnp.asarray(diff), jnp.asarray(eff)))
+
+
+@jax.jit
+def _label_rounds(diff: jax.Array, eff: jax.Array) -> jax.Array:
+    return _label_fixpoint(_foreground(diff, eff))[1]
 
 
 @jax.jit

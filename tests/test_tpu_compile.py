@@ -5,8 +5,9 @@ device programs compile here for a chip that is described, not attached:
 what Mosaic or XLA:TPU would refuse on the chip (block shapes, VMEM,
 lowering rules, device memory) fails these tests without one.  Sizes are
 the ``configs/mez_edge`` deployment's: 144x256 frames with the grid
-engine's 48-frame bucket, the labeler at its full-resolution group shape,
-and a 4096-lane fleet tick.
+engine's 48-frame bucket, the labeler at its two full-resolution group
+shapes (144x256, and 216x256 for packed yuv420), and a 4096-lane fleet
+tick.
 
 The topology is described inside a fixture, never at import: only one
 process may hold the TPU library, and every test worker imports this
@@ -78,14 +79,18 @@ def test_frame_knob_grid_compiles(one_chip, cs, knob4):
     assert changed.shape == (plan.n_settings, FRAMES)
 
 
-def test_label_group_compiles(one_chip):
+@pytest.mark.parametrize("gh,gw", [(H, W), (216, W)],
+                         ids=["144x256", "216x256"])
+def test_label_group_compiles(one_chip, gh, gw):
     from repro.core import grid_engine as GE
 
     s, f = 15, 32
     compiled = GE._label_group.lower(
-        _spec((s, f, H, W), jnp.float32, one_chip),
+        _spec((s, f, gh, gw), jnp.float32, one_chip),
         _spec((s, f), jnp.float32, one_chip)).compile()
-    assert compiled.out_info.shape == (s, f, H, W)
+    assert compiled.out_info.shape == (s, f, gh, gw)
+    # segmented scans, no gather: a gather runs about an element at a time
+    assert " gather(" not in compiled.as_text()
     # the whole labeler fits the chip with room to spare
     assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 30
 
